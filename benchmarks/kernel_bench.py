@@ -51,7 +51,7 @@ from repro.kernels import (local_block_attention, maple_spgemm, maple_spmm,
                            plan_partitioned_spmm, plan_search, plan_spgemm,
                            plan_spmm, plan_spmm_vjp, reorder_rows)
 from repro.kernels.autotune import fit_calibration, time_interleaved
-from repro.kernels.compat import tpu_compiler_params
+from repro.launch import compile_cache
 
 RECORDS: list = []
 
@@ -161,7 +161,7 @@ def _lane_buffer_reference(a: BlockCSR, plan, bn: int):
             ),
             out_shape=jax.ShapeDtypeStruct((g, lanes, m, n), jnp.float32),
             interpret=True,
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
         )(order, row, col, blocks, b3)
@@ -884,6 +884,7 @@ def main(argv=None):
 
     if args.check and args.only:
         ap.error("--check needs the full golden set; drop --only")
+    compile_cache.enable()
 
     run(smoke=args.smoke, only=args.only)
 

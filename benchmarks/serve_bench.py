@@ -38,6 +38,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_smoke_config
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.models.layers import init_sparse_linear
 from repro.serve import (BatcherConfig, ContinuousBatcher, FaultSchedule,
@@ -317,6 +318,7 @@ def main(argv=None):
                     help="fail when deterministic scheduling metrics "
                          "drift from BASELINE json")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     run(smoke=args.smoke)
 

@@ -20,7 +20,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -83,7 +83,7 @@ def pipeline_apply(stage_fn: Callable, mesh: Mesh, n_microbatches: int,
         inner, mesh=mesh,
         in_specs=(P(pod_axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(params_stacked, x)
 
 

@@ -213,22 +213,6 @@ def local_partition_execution():
         _ctx.partition_disabled = prev
 
 
-def abstract_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str]):
-    """Version-portable ``AbstractMesh`` constructor (no devices needed).
-
-    jax 0.4.x wants ``AbstractMesh((("data", 16), ("model", 16)))``; newer
-    jax wants ``AbstractMesh((16, 16), ("data", "model"))``.  Rule checks
-    (divisibility, spec selection) only need ``mesh.shape``, which both
-    expose as a name → size mapping.
-    """
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    except TypeError:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-
-
 def _mesh_axes_for(logical: AxisNames, mesh: Mesh) -> Optional[Tuple[str, ...]]:
     """Resolve one logical name to the mesh axes that exist on this mesh."""
     if logical is None:

@@ -1,5 +1,6 @@
-"""Pallas TPU kernels for the Maple dataflow (validated with interpret=True
-on CPU; see each kernel's module docstring for the hardware mapping)."""
+"""Pallas TPU kernels for the Maple dataflow: compiled through Mosaic on a
+TPU, interpreted on the CPU for tests (``ops`` decides which); see each
+kernel's module docstring for the hardware mapping."""
 
 from repro.kernels.autotune import (SearchReport, auto_plan, fit_calibration,
                                     load_calibration, plan_cache_clear,

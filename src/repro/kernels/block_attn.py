@@ -25,7 +25,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 
 def _kernel(
@@ -101,7 +100,7 @@ def block_attention_pallas(
     bk: int = 128,
     causal: bool = True,
     window: int = 0,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     s, h, hd = q.shape
     if s % bq or s % bk:
@@ -135,7 +134,7 @@ def block_attention_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((s // bq, bq, h, hd), q.dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(flat_map,

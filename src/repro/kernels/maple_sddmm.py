@@ -53,7 +53,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.accum import tile_bounds
-from repro.kernels.compat import tpu_compiler_params
 
 
 def sddmm_shard_meta(gather: np.ndarray, gather_live: np.ndarray,
@@ -130,7 +129,7 @@ def maple_sddmm_bsr_pallas(
     bm: int,
     bk: int,
     bn: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """``dA.blocks = (dC @ B^T)`` sampled at the block pattern.
 
@@ -169,7 +168,7 @@ def maple_sddmm_bsr_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((n_blocks, bm, bk), jnp.float32),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
     )(jnp.asarray(block_row, jnp.int32),
@@ -220,7 +219,7 @@ def maple_sddmm_csr_pallas(
     step_col: jax.Array,     # (n_lanes, steps) int32, -1 pads
     *,
     n_slots: int,            # m * la
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """``dA`` per A ELL slot, sampled through the forward plan.
 
@@ -270,7 +269,7 @@ def maple_sddmm_csr_pallas(
         out_shape=jax.ShapeDtypeStruct((n_slots + 1, 1), jnp.float32),
         interpret=interpret,
         # lanes write disjoint live slots but share the sacrificial one
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
     )(flat_order, flat_row, flat_col, dc_ell, b_ell_val, scatter_pos)

@@ -40,7 +40,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.accum import run_bounds
-from repro.kernels.compat import tpu_compiler_params
 
 
 def _kernel(
@@ -48,7 +47,7 @@ def _kernel(
     order,            # flat ELL slot of A consumed per step (0 on pads)
     step_row,         # output row per step; pads -> sacrificial row m
     step_col,         # B row (= A col id) per step, -1 on pads
-    # VMEM operands
+    # VMEM operands (the leading, squeezed axis is the gathered row)
     a_val_ref,        # (1, 1) A value of this step's slot (the ARB slot)
     b_row_ref,        # (1, lb) compressed B row panel (the BRB)
     pos_ref,          # (1, lb) int32 PSB positions for this slot's partials
@@ -76,17 +75,30 @@ def _kernel(
     # their precomputed positions in the output row.  Pad steps (col == -1)
     # zero the scalar; dead panel lanes carry pos == -1 and match nothing.
     live = step_col[base + s] >= 0
-    a = jnp.where(live, a_val_ref[0, 0], 0).astype(jnp.float32)
-    contrib = a * b_row_ref[0].astype(jnp.float32)          # (lb,)
-    pos = pos_ref[0]                                        # (lb,) int32
-    onehot = (pos[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (lb, lc), 1)).astype(jnp.float32)
-    psb_ref[...] += jnp.dot(
-        contrib, onehot, preferred_element_type=jnp.float32)[None, :]
+    a = jnp.where(live, a_val_ref[...].astype(jnp.float32), 0.0)
+    contrib = a * b_row_ref[...].astype(jnp.float32)        # (1, lb)
+    # onehot^T[j, u] = (pos[u] == j): built with positions along lanes, so
+    # no relayout; the "NT" contraction over u lands partial u on column
+    # pos[u].  Positions within one B row are distinct, so each PSB column
+    # receives at most one partial per step and HIGHEST keeps it exact.
+    onehot_t = (jax.lax.broadcasted_iota(jnp.int32, (lc, lb), 0)
+                == pos_ref[...]).astype(jnp.float32)        # (lc, lb)
+    psb_ref[...] += jax.lax.dot_general(
+        contrib, onehot_t, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)                 # (1, lc)
 
     @pl.when(is_last)
     def _flush():
         out_ref[...] = psb_ref[...].astype(out_ref.dtype)
+
+
+def _pad_last(x: jax.Array, width: int, fill) -> jax.Array:
+    pad = width - x.shape[-1]
+    if not pad:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)],
+                   constant_values=fill)
 
 
 def maple_spgemm_pallas(
@@ -99,7 +111,7 @@ def maple_spgemm_pallas(
     *,
     m: int,
     lc: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Raw plan-driven kernel (no pattern logic — see ops.maple_spgemm).
 
@@ -107,45 +119,58 @@ def maple_spgemm_pallas(
     sacrificial pad-step target, sliced off by the wrapper, which also
     compacts rows into the padded-CSR value vector using the plan's
     pattern.  Accumulation is f32 regardless of the value dtype.
+
+    TPU layout: every per-step operand is one row of a gathered array.
+    Each array is viewed as ``(rows, 1, width)`` so a block's last two
+    dims equal the array's (Mosaic's tiling rule), and the panel widths
+    ``lb``/``lc`` are zero-padded to whole 128-lane vregs (pad B values
+    are 0, pad positions -1 — they contribute nothing and the pad output
+    columns are sliced off).
     """
     _, lb = b_ell_val.shape
     lanes, steps = order.shape
+    lbp = -(-lb // 128) * 128
+    lcp = -(-lc // 128) * 128
 
     flat_order = order.reshape(-1).astype(jnp.int32)
     flat_row = step_row.reshape(-1).astype(jnp.int32)
     flat_col = step_col.reshape(-1).astype(jnp.int32)
+    a3 = a_val_flat.reshape(-1, 1, 1)
+    b3 = _pad_last(b_ell_val, lbp, 0)[:, None, :]
+    pos3 = _pad_last(scatter_pos.astype(jnp.int32), lbp, -1)[:, None, :]
 
-    kernel = functools.partial(_kernel, steps=steps, lb=lb, lc=lc)
-    return pl.pallas_call(
+    kernel = functools.partial(_kernel, steps=steps, lb=lbp, lc=lcp)
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(lanes, steps),
             in_specs=[
                 pl.BlockSpec(
-                    (1, 1),
-                    lambda l, s, o, r, c: (o[l * steps + s], 0)),
+                    (None, 1, 1),
+                    lambda l, s, o, r, c: (o[l * steps + s], 0, 0)),
                 # pad steps clamp their col to 0: a panel is still fetched
                 # (pads cost bandwidth, not correctness) but the zeroed
                 # scalar annihilates it.
                 pl.BlockSpec(
-                    (1, lb),
+                    (None, 1, lbp),
                     lambda l, s, o, r, c: (
-                        jnp.maximum(c[l * steps + s], 0), 0)),
+                        jnp.maximum(c[l * steps + s], 0), 0, 0)),
                 pl.BlockSpec(
-                    (1, lb),
-                    lambda l, s, o, r, c: (o[l * steps + s], 0)),
+                    (None, 1, lbp),
+                    lambda l, s, o, r, c: (o[l * steps + s], 0, 0)),
             ],
             out_specs=pl.BlockSpec(
-                (1, lc),
-                lambda l, s, o, r, c: (r[l * steps + s], 0)),
-            scratch_shapes=[pltpu.VMEM((1, lc), jnp.float32)],
+                (None, 1, lcp),
+                lambda l, s, o, r, c: (r[l * steps + s], 0, 0)),
+            scratch_shapes=[pltpu.VMEM((1, lcp), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((m + 1, lc), a_val_flat.dtype),
+        out_shape=jax.ShapeDtypeStruct((m + 1, 1, lcp), a_val_flat.dtype),
         interpret=interpret,
         # lanes write disjoint real rows but share the sacrificial pad row,
         # so the lane axis stays "arbitrary" rather than "parallel".
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
-    )(flat_order, flat_row, flat_col, a_val_flat, b_ell_val, scatter_pos)
+    )(flat_order, flat_row, flat_col, a3, b3, pos3)
+    return out[:, 0, :lc]

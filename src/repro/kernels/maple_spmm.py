@@ -55,7 +55,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.accum import run_bounds
-from repro.kernels.compat import tpu_compiler_params
 
 
 # --------------------------------------------------------------------------
@@ -96,7 +95,7 @@ def maple_spmm_batched_pallas(
     *,
     m: int,
     bn: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Naive-schedule SpMM over a batch of RHS (raw; padding in ops.py)."""
     n_blocks, bm, bk = blocks.shape
@@ -125,7 +124,7 @@ def maple_spmm_batched_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((g, m, n), b_dense.dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(block_row, safe_col, blocks, b_dense)
@@ -183,7 +182,7 @@ def maple_spmm_planned_pallas(
     *,
     m: int,
     bn: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Plan-driven fused SpMM.  Returns the merged ``(G, M, N)`` output in
     **f32** — partials of a split row are combined at full accumulator
@@ -242,7 +241,7 @@ def maple_spmm_planned_pallas(
         interpret=interpret,
         # lanes merge into shared output tiles -> sequential, NOT parallel;
         # the batch and output-tile axes stay parallel (disjoint tiles)
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary"),
         ),
@@ -295,7 +294,7 @@ def maple_spmm_compact_pallas(
     *,
     r_max: int,
     bn: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Plan-driven fused SpMM, compact-flush layout.  Returns per-lane
     flush tiles ``(G, L, r_max·bm, N)`` in **f32**, sized by the plan's
@@ -342,7 +341,7 @@ def maple_spmm_compact_pallas(
         out_shape=jax.ShapeDtypeStruct((g, lanes, r_max * bm, n),
                                        jnp.float32),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

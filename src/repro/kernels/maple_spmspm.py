@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 
 def _kernel(
@@ -63,7 +62,7 @@ def maple_spmspm_pallas(
     col_ids: jax.Array,   # (M, L) int32, -1 on pads
     b_rows: jax.Array,    # (K, N) row-addressable B (densified rows)
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     m, slots = values.shape
     k, n = b_rows.shape
@@ -84,7 +83,7 @@ def maple_spmspm_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), values.dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(flat_cols, values, b_rows)

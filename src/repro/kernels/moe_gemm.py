@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 
 def _kernel(
@@ -62,7 +61,7 @@ def moe_gemm_pallas(
     bt: int = 128,
     bf: int = 128,
     bd: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     t, d = x.shape
     e, dw, f = w.shape
@@ -88,7 +87,7 @@ def moe_gemm_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((t, f), x.dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(expert_of_tile, x, w)

@@ -2,8 +2,9 @@
 
 These wrappers own everything that is *not* the kernel: metadata
 construction, padding to tile multiples, empty-row masking, format
-conversion, and the interpret-mode switch (True on CPU — this container —
-so the kernel bodies execute in Python for validation; False on real TPU).
+conversion, and the interpret-mode switch — the only place it is decided:
+interpret on any backend but the TPU (the CPU test suite), compiled
+through Mosaic on a TPU.
 
 API:
   * :func:`maple_spmm`       — BlockCSR A × dense B      (MXU grain)
@@ -23,7 +24,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import formats
@@ -184,12 +185,12 @@ def maple_spmm(a: "formats.BlockFormat", b_dense: jax.Array, *,
     chunks of a split row happens *inside the planned kernel* (see
     ``kernels.maple_spmm`` and ``SpmmPlan.fused``) — no full ``(G,
     lanes, M, N)`` per-lane buffer is materialized, forward or backward.
-    On the rmw path (interpreted calls, the measured target) peak output
-    memory is the ``(G, M, N)`` result itself regardless of ``n_lanes``;
-    compiled calls take the compact path, whose flush tiles are bounded
-    by the plan's ``written`` map (``G·L·r_max·bm·N`` — typically ≪ the
-    retired buffer, equal to it only in the degenerate worst case where
-    some lane flushes every row).
+    The default (and, compiled, the only) layout is compact, whose
+    flush tiles are bounded by the plan's ``written`` map
+    (``G·L·r_max·bm·N`` — typically ≪ the retired buffer, equal to it
+    only in the degenerate worst case where some lane flushes every
+    row); the interpret-only rmw layout (``fused="rmw"``) keeps just the
+    ``(G, M, N)`` result.
 
     Empty block-rows never flush a PSB; their output tiles are explicitly
     zero-masked (naive path: from row_ptr; rmw planned path: from the
@@ -434,7 +435,7 @@ def _partitioned_spmm_f32(blocks, b3, plan: PartitionedSpmmPlan, *,
             mesh=mesh,
             in_specs=(P(ax_s), P(ax_s), P(ax_s), P(ax_s), P(ax_s),
                       P(None, None, ax_c)),
-            out_specs=P(ax_s, None, None, None, ax_c), check_rep=False)
+            out_specs=P(ax_s, None, None, None, ax_c), check_vma=False)
         tiles = shard_fn(shard_blocks, order, row, col, slot, b3p)
     elif mesh is not None:
         axis = axes
@@ -443,7 +444,7 @@ def _partitioned_spmm_f32(blocks, b3, plan: PartitionedSpmmPlan, *,
                 one_shard(blk[0], o[0], r[0], c[0], f[0], bb)[None],
             mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P()),
-            out_specs=P(axis), check_rep=False)
+            out_specs=P(axis), check_vma=False)
         tiles = shard_fn(shard_blocks, order, row, col, slot, b3)
     else:
         # stacked loop: full-N per shard — output-column tiles are
@@ -512,14 +513,14 @@ def _partitioned_sddmm_f32(dc, b3, train: SpmmTrainPlan, *, bn: int,
             shard_body, mesh=mesh,
             in_specs=(P(ax_s), P(ax_s), P(None, None, ax_c),
                       P(None, None, ax_c)),
-            out_specs=P(ax_s), check_rep=False)(rowd, cold, dcp, b3p)
+            out_specs=P(ax_s), check_vma=False)(rowd, cold, dcp, b3p)
     elif mesh is not None:
         axis = axes
         parts = shard_map(
             lambda r, c, dcl, bl: one_shard(r[0], c[0], dcl, bl)[None],
             mesh=mesh,
             in_specs=(P(axis), P(axis), P(), P()),
-            out_specs=P(axis), check_rep=False)(rowd, cold, dc, b3)
+            out_specs=P(axis), check_vma=False)(rowd, cold, dc, b3)
     else:
         if c_ > 1:
             dcp, _ = _pad_cols(dc, c_ * bn)

@@ -29,10 +29,18 @@ def make_production_mesh(*, multi_pod: bool = False):
             "the dry-run entrypoint must set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return _auto_mesh(shape, axes, devices[:need])
 
 
 def make_debug_mesh(shape=(1, 1), axes=("data", "model")):
     """Tiny mesh over however many devices exist (tests)."""
     need = math.prod(shape)
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:need])
+    return _auto_mesh(shape, axes, jax.devices()[:need])
+
+
+def _auto_mesh(shape, axes, devices):
+    # Auto axes: the model code places activations with
+    # with_sharding_constraint hints, which Explicit axes (jax.make_mesh's
+    # default) reject
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

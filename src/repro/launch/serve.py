@@ -12,9 +12,11 @@ import argparse
 import time
 
 import jax
+import jax.numpy as jnp
 
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.ft import checkpoint as ckpt
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.serve import SamplingConfig, generate
 
@@ -31,10 +33,14 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     key = jax.random.PRNGKey(args.seed)
-    params = lm.init_params(cfg, key)
+    # bf16 serving weights, made on the device: eager f32 init of a
+    # published-width model does not fit one 16 GB chip
+    params = jax.jit(lm.init_params, static_argnums=(0, 2))(
+        cfg, key, jnp.bfloat16)
     if args.ckpt_dir:
         _, restored = ckpt.load(args.ckpt_dir, {"params": params})
         params = restored["params"]

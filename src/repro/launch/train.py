@@ -21,6 +21,7 @@ from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.data import DataConfig, synth_batch
 from repro.ft import checkpoint as ckpt
 from repro.ft.straggler import StragglerMonitor, StepTimer
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.train import OptimizerConfig, init_opt_state, make_train_step
 
@@ -39,6 +40,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     ocfg = OptimizerConfig(peak_lr=args.lr, warmup_steps=5,

@@ -179,8 +179,14 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.float32):
                                          dtype=dtype)
                     for i, kind in enumerate(kinds)}
         ks = jax.random.split(key, count)
-        per = [one(k) for k in ks]
-        return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *per)
+        if cfg.sparse_mlp:
+            # BlockCSR weights are assembled from host metadata, which a
+            # vmapped trace cannot provide
+            per = [one(k) for k in ks]
+            return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *per)
+        # one traced layer instead of `count` unrolled copies: the stack is
+        # built once, not held twice, and init compiles in O(1) layers
+        return jax.vmap(one)(ks)
 
     cross = cfg.n_enc_layers > 0
     params: Dict[str, Any] = {

@@ -133,7 +133,7 @@ def moe_layer(p, cfg: MoEConfig, x, *, return_aux: bool = False):
 # The Maple mapping is unchanged — this is the same CSR-metadata walk, with
 # the NoC hop made explicit (DESIGN §3.3: Extensor's multicast ≈ all_to_all).
 
-from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.distributed.sharding import active_mesh  # noqa: E402
@@ -266,6 +266,6 @@ def moe_layer_ep(p, cfg: MoEConfig, x):
         inner, mesh=mesh,
         in_specs=(P(bspec, None, None), P(), wg_spec, wg_spec, wd_spec),
         out_specs=P(bspec, None, None),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["experts_gate"], p["experts_up"],
       p["experts_down"])

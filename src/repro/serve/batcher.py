@@ -124,17 +124,21 @@ class ContinuousBatcher:
         self.needs_kv = lm.needs_kv_pages(cfg)
         self.horizon = lm.history_horizon(cfg)
         self.allocator = PageAllocator(bcfg.n_pages, bcfg.page_size)
+        # the pool holds K/V in the parameters' dtype — the dtype prefill
+        # emits them in — so a bf16 model keeps a bf16 pool
         self.state = lm.init_paged_state(
             cfg, bcfg.max_slots, bcfg.n_pages, bcfg.page_size,
-            bcfg.max_pages)
+            bcfg.max_pages, dtype=params["embed_tokens"].dtype)
         self.slots: List[Optional[_Slot]] = [None] * bcfg.max_slots
         self._step_fn = jitted_decode_step(cfg, paged=True,
                                            return_hidden=head is not None)
         if head is not None:
-            # closed over the (pytree) weight + prebuilt plan: one compile,
-            # and the plan object is frozen into the callable — there is
-            # nothing a later admission could replan.
-            self._head_fn = jax.jit(lambda h: head(h))
+            # the prebuilt plan is frozen into the callable (nothing a later
+            # admission could replan); the weight is an argument, because a
+            # closed-over array is baked into each executable as a constant
+            # — another copy of the head in device memory per compile
+            score = jax.jit(lambda w, h: SparseLogitHead(w, head.plan)(h))
+            self._head_fn = lambda h: score(head.weight, h)
         self.completions: List[Completion] = []
         self.steps = 0
         self.rounds = 0              # step() calls — the fault-clock key

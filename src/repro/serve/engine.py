@@ -106,11 +106,9 @@ class SparseLogitHead:
     def __call__(self, hidden: jax.Array) -> jax.Array:
         """hidden: (B, S, D) → logits (B, S, V) in one batched launch.
 
-        The fused planned kernels merge cross-lane partials in-kernel:
-        on the rmw path (interpreted calls) peak output memory is the
-        logits themselves regardless of the plan's lane count, and the
-        compact path's flush tiles are bounded by the plan's ``written``
-        map rather than ``lanes × V`` — so the lane-buffer budget (and
+        The fused planned kernels merge cross-lane partials without a
+        per-lane buffer: the compact layout's flush tiles are bounded by
+        the plan's ``written`` map rather than ``lanes × V`` — so the lane-buffer budget (and
         the reduced-lane replanning it forced on wide vocab × token
         shapes) is gone with the ``(G, lanes, V, N)`` buffer itself."""
         return sparse_linear(self.weight, hidden, plan=self.plan)

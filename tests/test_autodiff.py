@@ -26,10 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # optional dev dep; see tests/README.md
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.csr import CSR, BlockCSR
 from repro.kernels import (maple_spgemm, maple_spmm, plan_spgemm,

@@ -25,6 +25,7 @@ SCRIPT = textwrap.dedent("""
     from repro.data import DataConfig, synth_batch
     from repro.distributed.sharding import param_shardings, use_mesh_rules
     from repro.ft import checkpoint as ckpt
+    from repro.launch.mesh import make_debug_mesh
     from repro.models import lm
     from repro.train import OptimizerConfig, init_opt_state, make_train_step
 
@@ -44,7 +45,7 @@ SCRIPT = textwrap.dedent("""
                 params, opt, _ = fn(params, opt, synth_batch(dcfg, s))
         return params, opt
 
-    mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_a = make_debug_mesh((4, 2), ("data", "model"))
     params = lm.init_params(cfg, jax.random.PRNGKey(0))
     opt = init_opt_state(ocfg, params)
 
@@ -58,7 +59,7 @@ SCRIPT = textwrap.dedent("""
         ckpt.save(d, 2, {"params": jax.device_get(p2),
                          "opt": jax.device_get(o2)})
         for shape in ((2, 2), (8, 1)):
-            mesh_b = jax.make_mesh(shape, ("data", "model"))
+            mesh_b = make_debug_mesh(shape, ("data", "model"))
             like = {"params": params, "opt": opt}
             sh = {"params": param_shardings(params, mesh_b),
                   "opt": param_shardings(opt, mesh_b)}

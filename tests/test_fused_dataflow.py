@@ -4,6 +4,7 @@ jaxpr), the fused layouts agree with each other and with the naive walk,
 and jit vs eager is bit-identical under a prebuilt plan."""
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -34,9 +35,9 @@ def _operands(seed=0, gm=GM, gk=GK):
 # --------------------------------------------------------------------------
 
 def _iter_jaxprs(x):
-    if isinstance(x, jax.core.ClosedJaxpr):
+    if isinstance(x, jax.extend.core.ClosedJaxpr):
         yield x.jaxpr
-    elif isinstance(x, jax.core.Jaxpr):
+    elif isinstance(x, jax.extend.core.Jaxpr):
         yield x
     elif isinstance(x, (list, tuple)):
         for item in x:
@@ -186,7 +187,7 @@ def test_rmw_requires_interpret_and_compiled_calls_take_compact():
             a.blocks, jnp.asarray(plan.order), jnp.asarray(plan.step_row),
             jnp.asarray(plan.step_col), jnp.asarray(plan.step_acc),
             b3, m=M, bn=N, interpret=False)
-    assert plan_spmm(a, n_lanes=LANES).fused == "rmw"   # auto preference
+    assert plan_spmm(a, n_lanes=LANES).fused == "compact"   # auto
     # trace (not execute) a compiled call: the rmw-preferring plan must
     # route through the compact flush tiles, never the rmw kernel raise
     def compiled(blocks, bb):

@@ -2,10 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # optional dev dep; see tests/README.md
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.csr import CSR
 from repro.core.gustavson import (dense_oracle, spmm_rowwise,

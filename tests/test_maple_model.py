@@ -2,10 +2,7 @@
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # optional dev dep; see tests/README.md
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 pytestmark = pytest.mark.tier1
 

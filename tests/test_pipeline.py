@@ -12,8 +12,9 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
     from repro.distributed.pipeline import pipeline_apply, stage_group_count
+    from repro.launch.mesh import make_debug_mesh
 
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = make_debug_mesh((4,), ("pod",))
     G, B, D = 8, 8, 16
     key = jax.random.PRNGKey(0)
     ws = jax.random.normal(key, (G, D, D)) * 0.1

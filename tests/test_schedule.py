@@ -281,13 +281,13 @@ def test_bf16_split_row_rounds_once(fused):
         raw = maple_spmm_planned_pallas(
             a.blocks, jnp.asarray(plan.order), jnp.asarray(plan.step_row),
             jnp.asarray(plan.step_col), jnp.asarray(plan.step_acc),
-            b[None], m=16, bn=16)
+            b[None], m=16, bn=16, interpret=True)
         assert raw.shape == (1, 16, 16)           # merged, no lane axis
     else:
         raw = maple_spmm_compact_pallas(
             a.blocks, jnp.asarray(plan.order), jnp.asarray(plan.step_row),
             jnp.asarray(plan.step_col), jnp.asarray(plan.flush_slot),
-            b[None], r_max=plan.r_max, bn=16)
+            b[None], r_max=plan.r_max, bn=16, interpret=True)
         assert raw.shape == (1, plan.n_lanes, plan.r_max * 8, 16)
     assert raw.dtype == jnp.float32
     # consequence: the split schedule matches the f32 product of the

@@ -3,9 +3,10 @@
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.distributed import sharding as sh
+from repro.launch.mesh import make_debug_mesh
 
 pytestmark = pytest.mark.tier1
 
@@ -14,12 +15,12 @@ pytestmark = pytest.mark.tier1
 def mesh():
     # 1 real device: use a (1, 1) mesh — rule *selection* logic is
     # device-count independent (divisibility uses axis sizes).
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_debug_mesh((1, 1), ("data", "model"))
 
 
 def mesh16():
     """Abstract 16×16 mesh for rule checks (no devices needed)."""
-    return sh.abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_divisibility_fallback():

@@ -5,10 +5,7 @@ sparse-output kernel against ``core.gustavson`` and the dense oracle."""
 import jax
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # optional dev dep; see tests/README.md
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.csr import (CSR, ell_slots, grow_nnz_max, merge_by_column,
                             spgemm_row_upper_bounds)
