@@ -150,6 +150,7 @@ def maple_sddmm_bsr_pallas(
     kernel = functools.partial(_bsr_kernel, n_g=g, n_j=n // bn)
     return pl.pallas_call(
         kernel,
+        name="maple_sddmm_bsr",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
@@ -240,6 +241,7 @@ def maple_sddmm_csr_pallas(
     kernel = functools.partial(_csr_kernel, steps=steps, lb=lb, lc=lc)
     return pl.pallas_call(
         kernel,
+        name="maple_sddmm_csr",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(lanes, steps),

@@ -142,6 +142,7 @@ def maple_spgemm_pallas(
     kernel = functools.partial(_kernel, steps=steps, lb=lbp, lc=lcp)
     out = pl.pallas_call(
         kernel,
+        name="maple_spgemm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(lanes, steps),

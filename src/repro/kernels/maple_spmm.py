@@ -110,6 +110,7 @@ def maple_spmm_batched_pallas(
     kernel = functools.partial(_batched_kernel, n_blocks=n_blocks)
     return pl.pallas_call(
         kernel,
+        name="maple_spmm_batched",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
@@ -220,6 +221,7 @@ def maple_spmm_planned_pallas(
     kernel = functools.partial(_planned_rmw_kernel, steps=steps)
     return pl.pallas_call(
         kernel,
+        name="maple_spmm_rmw",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=grid,
@@ -321,6 +323,7 @@ def maple_spmm_compact_pallas(
     kernel = functools.partial(_planned_compact_kernel, steps=steps)
     return pl.pallas_call(
         kernel,
+        name="maple_spmm_compact",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=grid,

@@ -71,6 +71,7 @@ def maple_spmspm_pallas(
     kernel = functools.partial(_kernel, slots=slots)
     return pl.pallas_call(
         kernel,
+        name="maple_spmspm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(m, slots),

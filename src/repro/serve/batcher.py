@@ -37,6 +37,12 @@ RequestQueue`, and (optionally) a plan-cached
    local-window/recurrent configs — reclamation of pages that fell
    behind the attention horizon.
 
+Every round leaves spans in the profiler's trace (``serve.round`` and,
+nested in it, ``serve.admit``/``serve.prefill``, ``serve.prepare``,
+``serve.decode``, ``serve.head``, ``serve.fetch``, ``serve.sample``;
+``serve/README.md`` lists them).  They cost under a microsecond each
+while no trace is being taken.
+
 Failure injection is deterministic: pass a
 :class:`~repro.serve.faults.FaultSchedule` and every fault lands on a
 fixed scheduling round — the chaos benchmark's metrics are exact-match
@@ -54,12 +60,13 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models import lm
 from repro.serve.engine import (SamplingConfig, SparseLogitHead,
                                 complete_static, jitted_decode_step,
-                                jitted_prefill, sample_token, token_entropy)
+                                jitted_prefill, named_jit, sample_token)
 from repro.serve.faults import FaultSchedule, TransientStepError
 from repro.serve.paged_cache import (DEAD_PAGE, PageAllocator,
                                      assert_paged_memory_bound, make_table,
@@ -76,7 +83,6 @@ class BatcherConfig:
     page_size: int = 8           # tokens per KV page
     n_pages: int = 64            # physical pool size (incl. dead page 0)
     max_seq: int = 128           # per-request prompt + new-token cap
-    collect_entropy: bool = False
     max_retries: int = 2         # fused-step replays before degrading
     preempt: bool = True         # evict lowest-progress slot when pages
     #                              run short (False = head-of-line block)
@@ -97,8 +103,6 @@ class _Slot:
     t_admit: float
     t_first: float
     steps: int = 0
-    pages_reclaimed: int = 0
-    entropy: List[float] = dataclasses.field(default_factory=list)
 
 
 class ContinuousBatcher:
@@ -136,13 +140,18 @@ class ContinuousBatcher:
             # the prebuilt plan is frozen into the callable (nothing a later
             # admission could replan); the weight is an argument, because a
             # closed-over array is baked into each executable as a constant
-            # — another copy of the head in device memory per compile
-            score = jax.jit(lambda w, h: SparseLogitHead(w, head.plan)(h))
-            self._head_fn = lambda h: score(head.weight, h)
+            # — another copy of the head in device memory per compile.
+            # Two programs over one callable, so a trace tells the head
+            # at decode from the head on a prefill's output.
+            def score(w, h):
+                return SparseLogitHead(w, head.plan)(h)
+            self._head_decode = named_jit(score, "head_decode")
+            self._head_prefill = named_jit(score, "head_prefill")
         self.completions: List[Completion] = []
         self.steps = 0
         self.rounds = 0              # step() calls — the fault-clock key
         self.occupancy_sum = 0       # Σ live slots per fused step
+        self.pages_in_use_sum = 0    # Σ allocator.in_use per fused step
         self.admitted = 0            # admissions incl. preemption resumes
         self.pages_reclaimed = 0     # freed behind the window horizon
         # --- failure-semantics counters (all deterministic) ---
@@ -265,42 +274,49 @@ class ContinuousBatcher:
     def _admit(self, req: Request, slot_id: int, n_pp: int,
                now: float) -> None:
         resumed = bool(req.generated)
-        ctx = (np.concatenate([req.tokens,
-                               np.asarray(req.generated, np.int32)])
-               if resumed else req.tokens)
-        total = int(ctx.size)
-        pages = self.allocator.alloc(n_pp) if n_pp else []
-        if resumed and self.needs_kv:
-            # leading logical pages already behind the horizon were not
-            # allocated (_prompt_pages): map them to the dead page —
-            # their prefill KV writes land there and are never read
-            dead = pages_for(total, self.bcfg.page_size) - n_pp
-            pages = [DEAD_PAGE] * dead + pages
-        padded_len = len(pages) * self.bcfg.page_size
-        prefill = jitted_prefill(self.cfg, max(padded_len, total),
-                                 return_hidden=self.head is not None)
-        out, pstate = prefill(self.params,
-                              batch={"tokens": jnp.asarray(
-                                  ctx, jnp.int32)[None]})
-        logits = (self._head_fn(out) if self.head is not None else out)
+        with TraceAnnotation("serve.admit", rid=req.rid,
+                             prompt_len=req.prompt_len, resumed=resumed):
+            ctx = (np.concatenate([req.tokens,
+                                   np.asarray(req.generated, np.int32)])
+                   if resumed else req.tokens)
+            total = int(ctx.size)
+            pages = self.allocator.alloc(n_pp) if n_pp else []
+            if resumed and self.needs_kv:
+                # leading logical pages already behind the horizon were
+                # not allocated (_prompt_pages): map them to the dead
+                # page — their prefill KV writes land there, never read
+                dead = pages_for(total, self.bcfg.page_size) - n_pp
+                pages = [DEAD_PAGE] * dead + pages
+            padded_len = max(len(pages) * self.bcfg.page_size, total)
+            prefill = jitted_prefill(self.cfg, padded_len,
+                                     return_hidden=self.head is not None)
+            with TraceAnnotation("serve.prefill", rid=req.rid,
+                                 padded_len=padded_len):
+                out, pstate = prefill(self.params,
+                                      batch={"tokens": jnp.asarray(
+                                          ctx, jnp.int32)[None]})
+            logits = (self._head_fn(out, prefill=True)
+                      if self.head is not None else out)
 
-        self.state = scatter_prefill_state(
-            self.state, pstate, slot_id, pages, self.bcfg.page_size)
+            self.state = scatter_prefill_state(
+                self.state, pstate, slot_id, pages, self.bcfg.page_size)
 
-        key = (req.resume_key if req.resume_key is not None
-               else jax.random.fold_in(self.key, req.rid))
-        slot = _Slot(req=req, pages=pages, pos=total,
-                     pending=0, out=list(req.generated), key=key,
-                     t_admit=(req.t_admit0 if resumed else now),
-                     t_first=(req.t_first0 if resumed else now),
-                     steps=req.steps0)
-        reason = self._sample(slot, logits[:, -1], now)
-        self.slots[slot_id] = slot
-        self.admitted += 1
-        if reason is not None:       # eos/length/error on the first token
-            if reason == STATUS_ERROR:
-                self.errors += 1
-            self._retire(slot_id, reason, now)
+            key = (req.resume_key if req.resume_key is not None
+                   else jax.random.fold_in(self.key, req.rid))
+            slot = _Slot(req=req, pages=pages, pos=total,
+                         pending=0, out=list(req.generated), key=key,
+                         t_admit=(req.t_admit0 if resumed else now),
+                         t_first=(req.t_first0 if resumed else now),
+                         steps=req.steps0)
+            with TraceAnnotation("serve.fetch"):
+                row = np.asarray(logits[:, -1])
+            reason = self._sample(slot, row, now)
+            self.slots[slot_id] = slot
+            self.admitted += 1
+            if reason is not None:   # eos/length/error on the first token
+                if reason == STATUS_ERROR:
+                    self.errors += 1
+                self._retire(slot_id, reason, now)
 
     # ------------------------------------------------------------------
     # sampling / retirement
@@ -309,24 +325,24 @@ class ContinuousBatcher:
     def _sample(self, slot: _Slot, logits_row, now: float):
         """Sample one token for a slot; returns a finish reason or None.
 
-        ``logits_row``: (1, V_padded).  Every slot draws from its own
-        fold_in key chain, so a request's sampled tokens do not depend on
-        which other requests share the batch.  A non-finite logits row
-        (over the REAL vocabulary — padded slots carry garbage by
-        design) is the quarantine signal: no token is sampled and the
+        ``logits_row``: (1, V_padded) on the host.  Every slot draws from
+        its own fold_in key chain, so a request's sampled tokens do not
+        depend on which other requests share the batch.  A non-finite
+        logits row (over the REAL vocabulary — padded slots carry garbage
+        by design) is the quarantine signal: no token is sampled and the
         slot retires with ``status="error"``.
         """
-        row = np.asarray(logits_row)
-        if not np.isfinite(row[0, :self.cfg.vocab_size]).all():
-            return STATUS_ERROR
-        slot.key, sub = jax.random.split(slot.key)
-        tok = int(sample_token(jnp.asarray(row), sub, self.sampling,
-                               self.cfg.vocab_size)[0])
+        with TraceAnnotation("serve.sample", rid=slot.req.rid):
+            row = np.asarray(logits_row)
+            with TraceAnnotation("serve.sample.check"):
+                finite = np.isfinite(row[0, :self.cfg.vocab_size]).all()
+            if not finite:
+                return STATUS_ERROR
+            with TraceAnnotation("serve.sample.draw"):
+                slot.key, sub = jax.random.split(slot.key)
+                tok = int(sample_token(jnp.asarray(row), sub, self.sampling,
+                                       self.cfg.vocab_size)[0])
         slot.out.append(tok)
-        if self.bcfg.collect_entropy:
-            slot.entropy.append(
-                float(token_entropy(jnp.asarray(row),
-                                    self.cfg.vocab_size)[0]))
         slot.pending = tok
         req = slot.req
         if req.eos_id >= 0 and tok == req.eos_id:
@@ -371,7 +387,6 @@ class ContinuousBatcher:
             if slot.pages[j] != DEAD_PAGE:
                 self.allocator.free([slot.pages[j]])
                 slot.pages[j] = DEAD_PAGE
-                slot.pages_reclaimed += 1
                 self.pages_reclaimed += 1
 
     # ------------------------------------------------------------------
@@ -433,10 +448,21 @@ class ContinuousBatcher:
                 self.errors += 1
             self._retire(i, reason, now)
 
+    def _head_fn(self, hidden, prefill: bool = False):
+        """Logits of ``hidden`` through the sparse head: ``jit_head_decode``
+        in the round, ``jit_head_prefill`` on a prefill's output."""
+        with TraceAnnotation("serve.head"):
+            fn = self._head_prefill if prefill else self._head_decode
+            return fn(self.head.weight, hidden)
+
     def step(self, now: float = 0.0) -> List[Completion]:
         """One scheduling round: expire, admit, fused-decode (with
         bounded retry), sample, retire.  Returns the requests that
         completed during this round."""
+        with TraceAnnotation("serve.round", round=self.rounds):
+            return self._round(now)
+
+    def _round(self, now: float) -> List[Completion]:
         before = len(self.completions)
         rnd = self.rounds
         self.rounds += 1
@@ -447,29 +473,30 @@ class ContinuousBatcher:
         if self.live() == 0:
             return self.completions[before:]
 
-        # grow write pages BEFORE assembling the batch: growth may evict
-        # a co-resident slot, and a victim already baked into the batch
-        # arrays would decode as a ghost into freed pages
-        for i in range(self.bcfg.max_slots):
-            if self.slots[i] is not None:
-                self._ensure_decode_page(i, now)
-        if self.live() == 0:
-            return self.completions[before:]
+        with TraceAnnotation("serve.prepare"):
+            # grow write pages BEFORE assembling the batch: growth may
+            # evict a co-resident slot, and a victim already baked into
+            # the batch arrays would decode as a ghost into freed pages
+            for i in range(self.bcfg.max_slots):
+                if self.slots[i] is not None:
+                    self._ensure_decode_page(i, now)
+            if self.live() == 0:
+                return self.completions[before:]
 
-        tokens = np.zeros((self.bcfg.max_slots, 1), np.int32)
-        pos = np.zeros((self.bcfg.max_slots,), np.int32)
-        pages: List[List[int]] = [[] for _ in range(self.bcfg.max_slots)]
-        for i, slot in enumerate(self.slots):
-            if slot is None:
-                continue
-            tokens[i, 0] = slot.pending
-            pos[i] = slot.pos
-            pages[i] = slot.pages
-        table = make_table(pages, self.bcfg.max_pages)
+            tokens = np.zeros((self.bcfg.max_slots, 1), np.int32)
+            pos = np.zeros((self.bcfg.max_slots,), np.int32)
+            pages: List[List[int]] = [[] for _ in range(self.bcfg.max_slots)]
+            for i, slot in enumerate(self.slots):
+                if slot is None:
+                    continue
+                tokens[i, 0] = slot.pending
+                pos[i] = slot.pos
+                pages[i] = slot.pages
+            table = make_table(pages, self.bcfg.max_pages)
 
-        state = dict(self.state)
-        state["table"] = jnp.asarray(table)
-        state["pos"] = jnp.asarray(pos)
+            state = dict(self.state)
+            state["table"] = jnp.asarray(table)
+            state["pos"] = jnp.asarray(pos)
 
         # bounded retry: every input (params, state dict, host arrays)
         # is immutable until the step succeeds, so a replay is exact.
@@ -478,28 +505,35 @@ class ContinuousBatcher:
         inject = (self.faults.transient_failures(rnd)
                   if self.faults is not None else 0)
         attempts = 0
-        while True:
-            try:
-                if attempts < inject:
-                    raise TransientStepError(
-                        f"injected transient failure (round {rnd}, "
-                        f"attempt {attempts})")
-                out, new_state = self._step_fn(self.params, state=state,
-                                               tokens=jnp.asarray(tokens))
-                break
-            except TransientStepError:
-                attempts += 1
-                if attempts > self.bcfg.max_retries:
-                    self._fallback_drain(now)
-                    return self.completions[before:]
-                self.retries += 1
+        with TraceAnnotation("serve.decode", live=self.live(),
+                             pages_in_use=self.allocator.in_use,
+                             pages_free=self.allocator.free_pages()):
+            while True:
+                try:
+                    if attempts < inject:
+                        raise TransientStepError(
+                            f"injected transient failure (round {rnd}, "
+                            f"attempt {attempts})")
+                    out, new_state = self._step_fn(
+                        self.params, state=state, tokens=jnp.asarray(tokens))
+                    break
+                except TransientStepError:
+                    attempts += 1
+                    if attempts > self.bcfg.max_retries:
+                        break
+                    self.retries += 1
+        if attempts > self.bcfg.max_retries:
+            self._fallback_drain(now)
+            return self.completions[before:]
 
         logits = (self._head_fn(out) if self.head is not None else out)
         self.state = new_state
         self.steps += 1
         self.occupancy_sum += self.live()
+        self.pages_in_use_sum += self.allocator.in_use
 
-        logits_host = np.asarray(logits[:, -1]).copy()
+        with TraceAnnotation("serve.fetch"):
+            logits_host = np.asarray(logits[:, -1]).copy()
         psn = (self.faults.poison_slot(rnd)
                if self.faults is not None else None)
         if psn is not None and 0 <= psn < self.bcfg.max_slots \
@@ -545,6 +579,7 @@ class ContinuousBatcher:
             self.allocator, self.bcfg.max_slots, self.bcfg.max_pages)
         stats["page_size"] = self.bcfg.page_size
         stats["reclaimed"] = self.pages_reclaimed
+        stats["pages_in_use_sum"] = self.pages_in_use_sum
         return stats
 
     def fault_stats(self) -> Dict[str, int]:

@@ -152,7 +152,7 @@ def token_entropy(logits, vocab_size: int):
 # --------------------------------------------------------------------------
 # jitted-callable cache: back-to-back generate()/engine calls must not
 # recompile.  jax.jit caches traces per *callable*, and a fresh
-# functools.partial is a fresh callable — so the partials are built once
+# functools.partial is a fresh callable — so the callables are built once
 # here, keyed on the (hashable, frozen) ModelConfig.
 # --------------------------------------------------------------------------
 
@@ -160,31 +160,46 @@ _PREFILL_JIT: Dict[tuple, Any] = {}
 _DECODE_JIT: Dict[tuple, Any] = {}
 
 
+def named_jit(fn, name: str):
+    """``jax.jit(fn)`` whose compiled program is named ``jit_<name>`` in
+    device traces (a jitted ``functools.partial`` or ``lambda`` comes out
+    as ``jit__unknown`` or ``jit__lambda``)."""
+    def call(*args, **kwargs):
+        return fn(*args, **kwargs)
+    call.__name__ = call.__qualname__ = name
+    return jax.jit(call)
+
+
 def jitted_prefill(cfg: ModelConfig, max_seq: int, *,
                    return_hidden: bool = False):
-    """Cached ``jax.jit(lm.prefill)`` for (cfg, max_seq)."""
+    """Cached ``jax.jit(lm.prefill)`` for (cfg, max_seq): ``jit_prefill``."""
     key = (cfg, int(max_seq), bool(return_hidden))
     fn = _PREFILL_JIT.get(key)
     if fn is None:
-        fn = jax.jit(functools.partial(lm.prefill, cfg=cfg,
-                                       max_seq=int(max_seq),
-                                       return_hidden=return_hidden))
+        fn = named_jit(functools.partial(lm.prefill, cfg=cfg,
+                                         max_seq=int(max_seq),
+                                         return_hidden=return_hidden),
+                       "prefill")
         _PREFILL_JIT[key] = fn
     return fn
 
 
 def jitted_decode_step(cfg: ModelConfig, *, paged: bool = False,
                        return_hidden: bool = False):
-    """Cached ``jax.jit(lm.decode_step)`` (or the paged variant) per cfg."""
+    """Cached ``jax.jit(lm.decode_step)`` per cfg (``jit_decode_static``),
+    or the paged variant the batcher fuses over its slots
+    (``jit_decode_step``)."""
     key = (cfg, bool(paged), bool(return_hidden))
     fn = _DECODE_JIT.get(key)
     if fn is None:
         if paged:
-            fn = jax.jit(functools.partial(lm.decode_step_paged, cfg=cfg,
-                                           return_hidden=return_hidden))
+            fn = named_jit(functools.partial(lm.decode_step_paged, cfg=cfg,
+                                             return_hidden=return_hidden),
+                           "decode_step")
         else:
-            fn = jax.jit(functools.partial(lm.decode_step, cfg=cfg,
-                                           return_hidden=return_hidden))
+            fn = named_jit(functools.partial(lm.decode_step, cfg=cfg,
+                                             return_hidden=return_hidden),
+                           "decode_static")
         _DECODE_JIT[key] = fn
     return fn
 

@@ -52,8 +52,11 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _has_kernel(compiled) -> bool:
-    return "tpu_custom_call" in compiled.as_text()
+def _has_kernel(compiled, name: str) -> bool:
+    """A Mosaic kernel, its op named after the ``pallas_call``'s ``name``
+    (what a device trace labels it by)."""
+    text = compiled.as_text()
+    return "tpu_custom_call" in text and f"%{name}." in text
 
 
 def test_compact_spmm_lowers(one_chip):
@@ -80,7 +83,7 @@ def test_compact_spmm_lowers(one_chip):
     compiled = _compile(
         fwd, _shape(one_chip, (rows.size, bs, bs), jnp.bfloat16),
         _shape(one_chip, (gk * bs, n), jnp.bfloat16))
-    assert _has_kernel(compiled)
+    assert _has_kernel(compiled, "maple_spmm_compact")
 
 
 def test_block_sddmm_lowers(one_chip):
@@ -93,7 +96,7 @@ def test_block_sddmm_lowers(one_chip):
         _shape(one_chip, (g, k, n), jnp.bfloat16),
         _shape(one_chip, (nb,), jnp.int32),
         _shape(one_chip, (nb,), jnp.int32))
-    assert _has_kernel(compiled)
+    assert _has_kernel(compiled, "maple_sddmm_bsr")
 
 
 def test_spgemm_numeric_lowers(one_chip):
@@ -106,7 +109,7 @@ def test_spgemm_numeric_lowers(one_chip):
         _shape(one_chip, (k, lb), jnp.float32),
         _shape(one_chip, (m * la, lb), jnp.int32),
         *[_shape(one_chip, (lanes, steps), jnp.int32)] * 3)
-    assert _has_kernel(compiled)
+    assert _has_kernel(compiled, "maple_spgemm")
 
 
 def test_qwen3_4b_bf16_decode_step_fits_one_chip(one_chip):
