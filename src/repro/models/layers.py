@@ -643,6 +643,24 @@ def init_sparse_linear(key, d_in: int, d_out: int, *,
     return BlockCSR.from_dense(np.asarray(dense.astype(dtype)), block_shape)
 
 
+def token_tiles(shape, bn: int = 128) -> tuple[bool, int]:
+    """How :func:`sparse_linear` tiles the tokens of an input of ``shape``:
+    ``(fold, tiles)``, the ``bn``-token tiles the kernel runs per weight
+    block.
+
+    A ``(B, S, d_in)`` input folds its batch into the token axis when that
+    runs fewer tiles, ``B·⌈S/bn⌉ > ⌈B·S/bn⌉``: decode's ``(B, 1, d_in)``
+    is then one tile, not ``B``.  Where both tile the same (``B = 1``, or
+    ``S`` a multiple of ``bn``) each batch element stays its own
+    right-hand side.  ``(T, d_in)`` and ``(d_in,)`` inputs are one
+    right-hand side already and never fold.
+    """
+    lead = tuple(shape[:-1])
+    tiles = -(-math.prod(lead) // bn)         # all tokens on one axis
+    fold = len(lead) == 2 and lead[0] * -(-lead[1] // bn) > tiles
+    return fold, tiles
+
+
 def sparse_linear(w: BlockCSR, x, *, plan=None, bn: int = 128,
                   schedule: str = "balanced", interpret=None):
     """``y = x @ Wᵀ`` for block-sparse ``W`` in ONE batched kernel launch.
@@ -651,8 +669,11 @@ def sparse_linear(w: BlockCSR, x, *, plan=None, bn: int = 128,
     are moved token-minor so they become the PSB columns of the kernel: a
     3D ``x`` maps each batch element to one dense right-hand side of the
     batched grid — the host never loops over ``B`` (the seed kernels
-    forced exactly that loop).  Ragged token counts are fine; the wrapper
-    pads to the ``bn`` tile and slices back.
+    forced exactly that loop) — unless folding the batch into one
+    ``(B·S)``-token right-hand side runs fewer ``bn``-token tiles
+    (:func:`token_tiles`; decode's one token per sequence).  Each output
+    column is the same block × column product either way.  Ragged token
+    counts are fine; the wrapper pads to the ``bn`` tile and slices back.
 
     Pass ``plan`` (from ``repro.kernels.plan_spmm``, or ``plan_spmm_vjp``
     when gradients must flow under jit) to amortize schedule construction
@@ -679,7 +700,7 @@ def sparse_linear(w: BlockCSR, x, *, plan=None, bn: int = 128,
     from repro.kernels import maple_spmm  # local: keep layers importable
     # without pulling pallas in for dense-only models
     d_out = w.shape[0]
-    if x.ndim == 3:
+    if x.ndim == 3 and not token_tiles(x.shape, bn)[0]:
         bt = jnp.swapaxes(x, 1, 2)                      # (B, d_in, S)
         y = maple_spmm(w, bt, bn=bn, plan=plan, schedule=schedule,
                        interpret=interpret)             # (B, d_out, S)

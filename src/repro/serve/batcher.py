@@ -64,6 +64,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models import lm
+from repro.models.layers import token_tiles
 from repro.serve.engine import (SamplingConfig, SparseLogitHead,
                                 complete_static, jitted_decode_step,
                                 jitted_prefill, named_jit, sample_token)
@@ -450,8 +451,12 @@ class ContinuousBatcher:
 
     def _head_fn(self, hidden, prefill: bool = False):
         """Logits of ``hidden`` through the sparse head: ``jit_head_decode``
-        in the round, ``jit_head_prefill`` on a prefill's output."""
-        with TraceAnnotation("serve.head"):
+        in the round, ``jit_head_prefill`` on a prefill's output.  The
+        span carries the tokens scored and the token tiles the kernel
+        runs for them (the round's slots fold into one)."""
+        b, s, _ = hidden.shape
+        _, tiles = token_tiles(hidden.shape)
+        with TraceAnnotation("serve.head", tokens=b * s, tiles=tiles):
             fn = self._head_prefill if prefill else self._head_decode
             return fn(self.head.weight, hidden)
 

@@ -105,6 +105,9 @@ class SparseLogitHead:
 
     def __call__(self, hidden: jax.Array) -> jax.Array:
         """hidden: (B, S, D) → logits (B, S, V) in one batched launch.
+        Decode's one token per sequence is scored as one ``B``-token
+        right-hand side, one token tile for all slots
+        (``layers.token_tiles``).
 
         The fused planned kernels merge cross-lane partials without a
         per-lane buffer: the compact layout's flush tiles are bounded by

@@ -1,4 +1,5 @@
-"""Suite-wide fixtures/hooks: per-test wall-clock timeouts.
+"""Suite-wide fixtures/hooks: per-test wall-clock timeouts, and the grids
+of a jaxpr's Pallas kernels.
 
 The container has no pytest-timeout plugin, so the timeout is a SIGALRM
 alarm around each test call: a hung kernel interpret run or subprocess
@@ -10,6 +11,7 @@ from __future__ import annotations
 
 import signal
 
+import jax
 import pytest
 
 DEFAULT_TIMEOUT_S = 300
@@ -37,3 +39,20 @@ def pytest_runtest_call(item):
     finally:
         signal.alarm(0)
         signal.signal(signal.SIGALRM, old)
+
+
+def _pallas_grids(jaxpr):
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            grids += _pallas_grids(sub)
+    return grids
+
+
+@pytest.fixture
+def pallas_grids():
+    """``pallas_grids(jaxpr)``: the grid of every ``pallas_call`` in a
+    jaxpr, nested ones included, in order."""
+    return _pallas_grids

@@ -371,3 +371,59 @@ def test_sparse_logit_head():
         logits, np.asarray(hidden) @ np.asarray(w.to_dense()).T,
         rtol=1e-4, atol=1e-4)
     assert head.predicted_cycles["plan"] >= 1.0
+
+
+@pytest.mark.parametrize("b,s", [(1, 1), (3, 1), (14, 1), (2, 16)])
+def test_sparse_linear_folds_batch_into_one_token_tile(b, s, pallas_grids):
+    """Decode-shaped ``(B, 1, D)`` inputs fold into one token tile and
+    score exactly as each batch element on its own right-hand side;
+    ``(B, S)`` with ``S`` a multiple of the tile keeps the batched
+    program as it was (grid ``G = B``); gradients flow through the fold."""
+    from repro.models import layers as L
+    from repro.serve.engine import SparseLogitHead
+    bn = 16
+    w = L.init_sparse_linear(jax.random.PRNGKey(3), 32, 64,
+                             block_shape=(8, 8), block_density=0.4)
+    plan = SparseLogitHead.build(w, n_lanes=4, trainable=True).plan
+    x = jnp.asarray(np.random.default_rng(b * 100 + s)
+                    .standard_normal((b, s, 32)).astype(np.float32))
+
+    def folded(xx):
+        return L.sparse_linear(w, xx, plan=plan, bn=bn)
+
+    def batched(xx):                  # one right-hand side per element
+        y = maple_spmm(w, jnp.swapaxes(xx, 1, 2), bn=bn, plan=plan)
+        return jnp.swapaxes(y, 1, 2)
+
+    fold, tiles = L.token_tiles(x.shape, bn)
+    assert fold == (b > 1 and s < bn)
+    assert tiles == -(-b * s // bn)
+    jaxpr = jax.make_jaxpr(folded)(x)
+    grid = pallas_grids(jaxpr.jaxpr)[0]
+    if fold:      # (G, lanes, token tiles, steps): every token, one tile
+        assert grid[0] == 1 and grid[2] == tiles == 1
+    else:
+        assert grid[0] == b and str(jaxpr) == str(jax.make_jaxpr(batched)(x))
+
+    y, ref = np.asarray(folded(x)), np.asarray(batched(x))
+    assert y.shape == (b, s, 64)
+    # each column is the same block x column product: bitwise on the CPU
+    np.testing.assert_array_equal(y, ref)
+    np.testing.assert_array_equal(y.argmax(-1), ref.argmax(-1))
+
+    wd = jnp.asarray(w.to_dense())
+    cot = jnp.asarray(np.random.default_rng(1)
+                      .standard_normal((b, s, 64)).astype(np.float32))
+    ga, gx = jax.grad(lambda blk, xx: jnp.sum(L.sparse_linear(
+        L.BlockCSR(blk, w.block_col, w.block_row, w.row_ptr, w.shape,
+                   w.block_shape), xx, plan=plan, bn=bn) * cot),
+        argnums=(0, 1))(w.blocks, x)
+    gwd, gxd = jax.grad(lambda dd, xx: jnp.sum((xx @ dd.T) * cot),
+                        argnums=(0, 1))(wd, x)
+    np.testing.assert_allclose(np.asarray(gx), np.asarray(gxd),
+                               rtol=1e-4, atol=1e-4)
+    pattern = np.asarray(wd) != 0
+    np.testing.assert_allclose(
+        np.asarray(L.BlockCSR(ga, w.block_col, w.block_row, w.row_ptr,
+                              w.shape, w.block_shape).to_dense()),
+        np.asarray(gwd) * pattern, rtol=1e-4, atol=1e-4)

@@ -178,6 +178,21 @@ def test_spans_agree_with_counters(runs):
 
 
 @pytest.mark.tier1
+def test_head_spans_carry_tokens_and_tiles(runs):
+    """A decode round scores every slot in one token tile; an admission
+    scores the prefill's last position, one token in one tile."""
+    eng, _ = runs["traced"]
+    spans = runs["spans"]
+    parents = _parents(spans)
+    heads = [(spans[p][0], s[3]) for s, p in zip(spans, parents)
+             if s[0] == "serve.head"]
+    assert [(a["tokens"], a["tiles"]) for p, a in heads
+            if p == "serve.round"] == [(eng.bcfg.max_slots, 1)] * eng.steps
+    assert [(a["tokens"], a["tiles"]) for p, a in heads
+            if p == "serve.admit"] == [(1, 1)] * eng.admitted
+
+
+@pytest.mark.tier1
 def test_pages_in_use_sum_hand_count(runs):
     """At a request's k-th fused step its slot holds the pages up to the
     one its token is written to: ``(prompt + k - 1) // page + 1``; it

@@ -22,6 +22,7 @@ from repro.kernels import maple_spmm, plan_spmm
 from repro.kernels.maple_sddmm import maple_sddmm_bsr_pallas
 from repro.kernels.maple_spgemm import maple_spgemm_pallas
 from repro.models import lm
+from repro.models.layers import sparse_linear
 
 HBM_BYTES = 15.75e9          # v5e HBM as the compiler reports it
 
@@ -134,3 +135,46 @@ def test_qwen3_4b_bf16_decode_step_fits_one_chip(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert used < HBM_BYTES, f"decode step needs {used / 1e9:.2f} GB"
+
+
+def test_qwen3_4b_head_decode_scores_all_slots_in_one_tile(one_chip,
+                                                         pallas_grids):
+    """``jit_head_decode`` of the qwen3-4b serving head (128x128 blocks at
+    density 0.5, bf16) on 14 slots of one token: the compact kernel runs
+    one token tile for all slots, and its temporaries are under a tenth
+    of scoring each slot as its own padded right-hand side."""
+    cfg = get_config("qwen3-4b")
+    slots, bs = 14, 128
+    gm, gk = cfg.vocab_padded // bs, cfg.d_model // bs
+    mask = np.random.default_rng(0).random((gm, gk)) < 0.5
+    mask[np.arange(gm), np.arange(gm) % gk] = True    # no dead block-row
+    rows, cols = np.nonzero(mask)
+    row_ptr = np.zeros(gm + 1, np.int32)
+    np.cumsum(np.bincount(rows, minlength=gm), out=row_ptr[1:])
+    meta = BlockCSR(np.zeros((rows.size, 1, 1), np.float32), cols, rows,
+                    row_ptr, (gm * bs, gk * bs), (bs, bs))
+    plan = plan_spmm(meta)
+
+    def weight(blocks):
+        return BlockCSR(blocks, jnp.asarray(cols, jnp.int32),
+                        jnp.asarray(rows, jnp.int32), jnp.asarray(row_ptr),
+                        meta.shape, meta.block_shape)
+
+    def head_decode(blocks, hidden):
+        return sparse_linear(weight(blocks), hidden, plan=plan,
+                             interpret=False)
+
+    def per_slot(blocks, hidden):     # each slot its own 128-token tile
+        y = maple_spmm(weight(blocks), jnp.swapaxes(hidden, 1, 2),
+                       plan=plan, interpret=False)
+        return jnp.swapaxes(y, 1, 2)
+
+    args = (_shape(one_chip, (rows.size, bs, bs), jnp.bfloat16),
+            _shape(one_chip, (slots, 1, cfg.d_model), jnp.bfloat16))
+    (grid,) = pallas_grids(jax.make_jaxpr(head_decode)(*args).jaxpr)
+    assert (grid[0], grid[2]) == (1, 1)           # (G, lanes, tiles, steps)
+    folded = _compile(head_decode, *args)
+    assert _has_kernel(folded, "maple_spmm_compact")
+    temp = folded.memory_analysis().temp_size_in_bytes
+    unfolded = _compile(per_slot, *args).memory_analysis().temp_size_in_bytes
+    assert temp * 10 < unfolded, (temp, unfolded)
