@@ -2,7 +2,8 @@ from repro.serve.batcher import BatcherConfig, ContinuousBatcher
 from repro.serve.engine import (SamplingConfig, SparseLogitHead,
                                 complete_static, generate,
                                 jitted_decode_step, jitted_prefill,
-                                sample_token, token_entropy)
+                                jitted_sampler, sample_token, sample_tokens,
+                                token_entropy)
 from repro.serve.faults import (FaultSchedule, TransientStepError,
                                 apply_malformed, corrupt_tokens)
 from repro.serve.paged_cache import PageAllocator
@@ -17,4 +18,5 @@ __all__ = ["BatcherConfig", "Completion", "ContinuousBatcher",
            "STATUS_LENGTH", "STATUS_OK", "STATUS_REJECTED",
            "TransientStepError", "apply_malformed", "complete_static",
            "corrupt_tokens", "generate", "jitted_decode_step",
-           "jitted_prefill", "sample_token", "token_entropy"]
+           "jitted_prefill", "jitted_sampler", "sample_token",
+           "sample_tokens", "token_entropy"]

@@ -29,19 +29,23 @@ RequestQueue`, and (optionally) a plan-cached
    the step exactly; after ``max_retries`` are exhausted, the round
    degrades gracefully — each live slot finishes on the static
    per-request path (``engine.complete_static``).
-4. **Sample/retire** — per-slot sampling (each request carries its own
-   fold_in-derived key, so its draws are independent of batch
-   composition), EOS/length retirement, a **non-finite-logits guard**
-   (a slot producing NaN/inf logits retires with ``status="error"``
-   while every co-resident slot is untouched), page freeing, and — for
-   local-window/recurrent configs — reclamation of pages that fell
-   behind the attention horizon.
+4. **Sample/retire** — one device program (``jit_sample``) draws every
+   slot's token and checks its logits for non-finite values, so only
+   ``(max_slots,)`` token ids and finite flags cross to the host.  Each
+   request carries its own fold_in-derived key chain, one row of a
+   ``(max_slots, 2)`` device array, so its draws are independent of
+   batch composition.  Then, per slot on the host: EOS/length
+   retirement, a **non-finite-logits guard** (a slot producing NaN/inf
+   logits retires with ``status="error"`` while every co-resident slot
+   is untouched), page freeing, and — for local-window/recurrent
+   configs — reclamation of pages that fell behind the attention
+   horizon.
 
 Every round leaves spans in the profiler's trace (``serve.round`` and,
 nested in it, ``serve.admit``/``serve.prefill``, ``serve.prepare``,
-``serve.decode``, ``serve.head``, ``serve.fetch``, ``serve.sample``;
-``serve/README.md`` lists them).  They cost under a microsecond each
-while no trace is being taken.
+``serve.decode``, ``serve.head``, ``serve.sample.draw``,
+``serve.fetch``, ``serve.sample``; ``serve/README.md`` lists them).
+They cost under a microsecond each while no trace is being taken.
 
 Failure injection is deterministic: pass a
 :class:`~repro.serve.faults.FaultSchedule` and every fault lands on a
@@ -67,7 +71,7 @@ from repro.models import lm
 from repro.models.layers import token_tiles
 from repro.serve.engine import (SamplingConfig, SparseLogitHead,
                                 complete_static, jitted_decode_step,
-                                jitted_prefill, named_jit, sample_token)
+                                jitted_prefill, jitted_sampler, named_jit)
 from repro.serve.faults import FaultSchedule, TransientStepError
 from repro.serve.paged_cache import (DEAD_PAGE, PageAllocator,
                                      assert_paged_memory_bound, make_table,
@@ -100,7 +104,6 @@ class _Slot:
     pos: int                     # next write position (tokens so far)
     pending: int                 # last sampled token, not yet fed
     out: List[int]
-    key: jax.Array
     t_admit: float
     t_first: float
     steps: int = 0
@@ -135,6 +138,12 @@ class ContinuousBatcher:
             cfg, bcfg.max_slots, bcfg.n_pages, bcfg.page_size,
             bcfg.max_pages, dtype=params["embed_tokens"].dtype)
         self.slots: List[Optional[_Slot]] = [None] * bcfg.max_slots
+        # each slot's sampling key chain, one row per slot; a row is
+        # written at admission and read back only on preemption and by
+        # the static fallback
+        self.keys = jnp.broadcast_to(self.key,
+                                     (bcfg.max_slots,) + self.key.shape)
+        self._sampler = jitted_sampler(sampling, cfg.vocab_size)
         self._step_fn = jitted_decode_step(cfg, paged=True,
                                            return_hidden=head is not None)
         if head is not None:
@@ -263,7 +272,7 @@ class ContinuousBatcher:
         if live:
             self.allocator.free(live)
         req.generated = list(slot.out)
-        req.resume_key = slot.key
+        req.resume_key = self.keys[slot_id]
         req.preemptions += 1
         req.t_admit0 = slot.t_admit
         req.t_first0 = slot.t_first
@@ -304,14 +313,14 @@ class ContinuousBatcher:
 
             key = (req.resume_key if req.resume_key is not None
                    else jax.random.fold_in(self.key, req.rid))
+            tokens, finite, keys = self._draw(logits, key[None], live=1)
+            self.keys = self.keys.at[slot_id].set(keys[0])
             slot = _Slot(req=req, pages=pages, pos=total,
-                         pending=0, out=list(req.generated), key=key,
+                         pending=0, out=list(req.generated),
                          t_admit=(req.t_admit0 if resumed else now),
                          t_first=(req.t_first0 if resumed else now),
                          steps=req.steps0)
-            with TraceAnnotation("serve.fetch"):
-                row = np.asarray(logits[:, -1])
-            reason = self._sample(slot, row, now)
+            reason = self._sample(slot, (tokens[0], finite[0]), now)
             self.slots[slot_id] = slot
             self.admitted += 1
             if reason is not None:   # eos/length/error on the first token
@@ -323,34 +332,45 @@ class ContinuousBatcher:
     # sampling / retirement
     # ------------------------------------------------------------------
 
-    def _sample(self, slot: _Slot, logits_row, now: float):
-        """Sample one token for a slot; returns a finish reason or None.
+    def _draw(self, logits, keys, poison: int = -1, *, live: int):
+        """Every row's token on the device in one ``jit_sample`` call;
+        only the token ids and finite flags are copied to the host.
 
-        ``logits_row``: (1, V_padded) on the host.  Every slot draws from
-        its own fold_in key chain, so a request's sampled tokens do not
-        depend on which other requests share the batch.  A non-finite
-        logits row (over the REAL vocabulary — padded slots carry garbage
-        by design) is the quarantine signal: no token is sampled and the
-        slot retires with ``status="error"``.
+        ``logits``: (B, S, V_padded), the last position is sampled;
+        ``keys``: (B, 2) key chains; ``poison``: a row whose logits count
+        as non-finite (-1: none).  Returns ``(tokens, finite)`` on the
+        host and the advanced key chains, still on the device."""
+        with TraceAnnotation("serve.sample.draw", live=live):
+            tokens, finite, keys = self._sampler(logits, keys, poison)
+        with TraceAnnotation("serve.fetch",
+                             bytes=tokens.nbytes + finite.nbytes):
+            tokens, finite = jax.device_get((tokens, finite))
+        return tokens, finite, keys
+
+    def _sample(self, slot: _Slot, drawn, now: float):
+        """Record one slot's drawn token; returns a finish reason or None.
+
+        ``drawn``: ``(token, finite)`` on the host, the slot's row of
+        :meth:`_draw`.  Every slot draws from its own fold_in key chain,
+        so a request's sampled tokens do not depend on which other
+        requests share the batch.  A non-finite logits row (over the REAL
+        vocabulary — padded slots carry garbage by design) is the
+        quarantine signal: no token is recorded and the slot retires
+        with ``status="error"``.
         """
+        tok, finite = drawn
         with TraceAnnotation("serve.sample", rid=slot.req.rid):
-            row = np.asarray(logits_row)
-            with TraceAnnotation("serve.sample.check"):
-                finite = np.isfinite(row[0, :self.cfg.vocab_size]).all()
             if not finite:
                 return STATUS_ERROR
-            with TraceAnnotation("serve.sample.draw"):
-                slot.key, sub = jax.random.split(slot.key)
-                tok = int(sample_token(jnp.asarray(row), sub, self.sampling,
-                                       self.cfg.vocab_size)[0])
-        slot.out.append(tok)
-        slot.pending = tok
-        req = slot.req
-        if req.eos_id >= 0 and tok == req.eos_id:
-            return "eos"
-        if len(slot.out) >= req.max_new_tokens:
-            return "length"
-        return None
+            tok = int(tok)
+            slot.out.append(tok)
+            slot.pending = tok
+            req = slot.req
+            if req.eos_id >= 0 and tok == req.eos_id:
+                return "eos"
+            if len(slot.out) >= req.max_new_tokens:
+                return "length"
+            return None
 
     def _retire(self, slot_id: int, reason: str, now: float) -> None:
         slot = self.slots[slot_id]
@@ -439,10 +459,10 @@ class ContinuousBatcher:
             ctx = (np.concatenate([req.tokens,
                                    np.asarray(slot.out, np.int32)])
                    if slot.out else req.tokens)
-            new_toks, reason, slot.key = complete_static(
+            new_toks, reason, _ = complete_static(
                 self.params, self.cfg, ctx,
                 req.max_new_tokens - len(slot.out),
-                sampling=self.sampling, key=slot.key, eos_id=req.eos_id,
+                sampling=self.sampling, key=self.keys[i], eos_id=req.eos_id,
                 head=self.head)
             slot.out.extend(new_toks)
             if reason == STATUS_ERROR:
@@ -537,19 +557,17 @@ class ContinuousBatcher:
         self.occupancy_sum += self.live()
         self.pages_in_use_sum += self.allocator.in_use
 
-        with TraceAnnotation("serve.fetch"):
-            logits_host = np.asarray(logits[:, -1]).copy()
+        # a poisoned row that is free or out of range flags no live slot
         psn = (self.faults.poison_slot(rnd)
                if self.faults is not None else None)
-        if psn is not None and 0 <= psn < self.bcfg.max_slots \
-                and self.slots[psn] is not None:
-            logits_host[psn, :] = np.nan
+        tokens, finite, self.keys = self._draw(
+            logits, self.keys, -1 if psn is None else psn, live=self.live())
         for i, slot in enumerate(self.slots):
             if slot is None:
                 continue
             slot.pos += 1
             slot.steps += 1
-            reason = self._sample(slot, logits_host[i][None], now)
+            reason = self._sample(slot, (tokens[i], finite[i]), now)
             if reason is not None:
                 if reason == STATUS_ERROR:
                     self.errors += 1

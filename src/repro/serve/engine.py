@@ -139,6 +139,26 @@ def sample_token(logits, key, cfg: SamplingConfig, vocab_size: int):
     return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
 
 
+def sample_tokens(logits, keys, poison, cfg: SamplingConfig,
+                  vocab_size: int):
+    """Every row's token in one program: ``sample_token`` over rows.
+
+    logits: (B, V_padded); keys: (B, 2), one key chain per row; poison: a
+    row index whose logits count as non-finite (-1: none).  Row ``b``
+    splits its key and draws with the sub key, the same chain a per-row
+    loop of ``split`` + ``sample_token`` walks.  Returns ``(tokens (B,)
+    int32, finite (B,) bool, keys (B, 2))``: ``finite`` is over the REAL
+    vocabulary only (padded ids carry garbage by design).
+    """
+    def row(lg, key):
+        key, sub = jax.random.split(key)
+        tok = sample_token(lg[None], sub, cfg, vocab_size)[0]
+        return tok, jnp.isfinite(lg[:vocab_size]).all(), key
+    tokens, finite, keys = jax.vmap(row)(logits, keys)
+    finite = finite & (jnp.arange(logits.shape[0]) != poison)
+    return tokens, finite, keys
+
+
 def token_entropy(logits, vocab_size: int):
     """Per-row softmax entropy over the REAL vocabulary.
 
@@ -161,6 +181,7 @@ def token_entropy(logits, vocab_size: int):
 
 _PREFILL_JIT: Dict[tuple, Any] = {}
 _DECODE_JIT: Dict[tuple, Any] = {}
+_SAMPLE_JIT: Dict[tuple, Any] = {}
 
 
 def named_jit(fn, name: str):
@@ -204,6 +225,19 @@ def jitted_decode_step(cfg: ModelConfig, *, paged: bool = False,
                                              return_hidden=return_hidden),
                            "decode_static")
         _DECODE_JIT[key] = fn
+    return fn
+
+
+def jitted_sampler(cfg: SamplingConfig, vocab_size: int):
+    """Cached ``jit_sample``: ``sample_tokens`` on the last position of
+    a model output ``(B, S, V_padded)``, so the slice rides in the same
+    program.  One compile per batch width."""
+    key = (cfg, int(vocab_size))
+    fn = _SAMPLE_JIT.get(key)
+    if fn is None:
+        fn = named_jit(lambda logits, keys, poison: sample_tokens(
+            logits[:, -1], keys, poison, cfg, int(vocab_size)), "sample")
+        _SAMPLE_JIT[key] = fn
     return fn
 
 

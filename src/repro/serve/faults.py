@@ -18,8 +18,9 @@ the top of every :meth:`~repro.serve.batcher.ContinuousBatcher.step`):
   buffers are only committed on success); ``k`` ≤ ``max_retries`` is
   absorbed invisibly, ``k`` > ``max_retries`` degrades that round to
   the static per-request path.
-* **NaN-logit poisoning** — ``poison[round] = slot`` overwrites that
-  slot's logits row with NaN after the fused step, simulating a
+* **NaN-logit poisoning** — ``poison[round] = slot`` makes the
+  round's sampler count that slot's logits row as non-finite (the row
+  index is an argument of the same compiled program), simulating a
   device-side numeric fault confined to one sequence.  The engine's
   non-finite guard retires the slot with ``status="error"``; every
   co-resident slot must be unaffected (the bit-identity pin).
@@ -75,8 +76,8 @@ class FaultSchedule:
         return int(self.transient.get(rnd, 0))
 
     def poison_slot(self, rnd: int) -> Optional[int]:
-        """Slot whose logits are NaN-poisoned after round ``rnd``'s
-        fused step (None = no poisoning this round)."""
+        """Slot whose logits count as non-finite in round ``rnd``'s
+        sampler (None = no poisoning this round)."""
         return self.poison.get(rnd)
 
     def alloc_denied(self, rnd: int) -> bool:

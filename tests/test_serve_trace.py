@@ -113,25 +113,25 @@ def test_spans_nest_by_cause(runs):
                "serve.prepare": {"serve.round"},
                "serve.decode": {"serve.round"},
                "serve.head": {"serve.admit", "serve.round"},
+               "serve.sample.draw": {"serve.admit", "serve.round"},
                "serve.fetch": {"serve.admit", "serve.round"},
-               "serve.sample": {"serve.admit", "serve.round"},
-               "serve.sample.check": {"serve.sample"},
-               "serve.sample.draw": {"serve.sample"}}
+               "serve.sample": {"serve.admit", "serve.round"}}
     spans = runs["spans"]
     parents = _parents(spans)
     assert {s[0] for s in spans} == set(allowed)
     for span, p in zip(spans, parents):
         assert (spans[p][0] if p is not None else None) \
             in allowed[span[0]], span
-    # an admission prefills, scores the prefill, fetches and samples once
+    # an admission prefills, scores the prefill, draws on the device,
+    # fetches and records its token once; recording opens no span
     kids = _children(spans, parents)
     for i, span in enumerate(spans):
         names = sorted(k[0] for k in kids[i])
         if span[0] == "serve.admit":
             assert names == ["serve.fetch", "serve.head", "serve.prefill",
-                             "serve.sample"]
-        if span[0] == "serve.sample":
-            assert names == ["serve.sample.check", "serve.sample.draw"]
+                             "serve.sample", "serve.sample.draw"]
+        if span[0] in ("serve.sample", "serve.sample.draw"):
+            assert names == []
 
 
 @pytest.mark.tier1
@@ -149,8 +149,9 @@ def test_spans_agree_with_counters(runs):
     # the decode span carries the live slots; they add up to occupancy
     assert sum(s[3]["live"] for s in spans
                if s[0] == "serve.decode") == eng.occupancy_sum
-    # once per decoding round: one decode, one head, one fetch, and one
-    # sample per live slot
+    # once per decoding round: one decode, one head, one draw over the
+    # live slots, one fetch of ids and flags (4 + 1 bytes a slot), and
+    # one sample per live slot
     for i in (i for i, s in enumerate(spans) if s[0] == "serve.round"):
         names = collections.Counter(k[0] for k in kids[i])
         decode = [k for k in kids[i] if k[0] == "serve.decode"]
@@ -158,8 +159,19 @@ def test_spans_agree_with_counters(runs):
             assert names.keys() <= {"serve.admit"}
             continue
         assert names["serve.decode"] == names["serve.head"] == \
-            names["serve.fetch"] == names["serve.prepare"] == 1
-        assert names["serve.sample"] == decode[0][3]["live"]
+            names["serve.sample.draw"] == names["serve.fetch"] == \
+            names["serve.prepare"] == 1
+        live = decode[0][3]["live"]
+        assert names["serve.sample"] == live
+        (draw,) = [k for k in kids[i] if k[0] == "serve.sample.draw"]
+        assert draw[3]["live"] == live
+        (fetch,) = [k for k in kids[i] if k[0] == "serve.fetch"]
+        assert fetch[3]["bytes"] == eng.bcfg.max_slots * 5
+    # an admission draws and fetches one row
+    for i in (i for i, s in enumerate(spans) if s[0] == "serve.admit"):
+        assert {k[0]: k[3] for k in kids[i]
+                if k[0] in ("serve.sample.draw", "serve.fetch")} == \
+            {"serve.sample.draw": {"live": 1}, "serve.fetch": {"bytes": 5}}
     # the request's rid on its admission and on each token it sampled
     rids = {c.rid for c in comps}
     admits = [s[3] for s in spans if s[0] == "serve.admit"]
@@ -225,7 +237,7 @@ def test_tracing_changes_no_output_and_no_counter(runs):
 @pytest.mark.tier1
 def test_serving_programs_carry_stable_names(runs):
     """The programs a trace names: the fused step, each prefill length,
-    and the head at decode and on a prefill's output."""
+    the head at decode and on a prefill's output, and the sampler."""
     eng, _ = runs["plain"]
     state = dict(eng.state)
     tokens = jax.numpy.zeros((eng.bcfg.max_slots, 1), jax.numpy.int32)
@@ -240,6 +252,10 @@ def test_serving_programs_carry_stable_names(runs):
             eng.params, batch=prompt),
         "jit_head_decode": eng._head_decode.lower(eng.head.weight, hidden),
         "jit_head_prefill": eng._head_prefill.lower(eng.head.weight, hidden),
+        "jit_sample": eng._sampler.lower(
+            jax.ShapeDtypeStruct((eng.bcfg.max_slots, 1,
+                                  eng.cfg.vocab_padded), jax.numpy.float32),
+            eng.keys, -1),
     }
     for name, low in lowered.items():
         assert low.as_text().startswith(f"module @{name} "), name

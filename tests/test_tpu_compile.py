@@ -23,6 +23,7 @@ from repro.kernels.maple_sddmm import maple_sddmm_bsr_pallas
 from repro.kernels.maple_spgemm import maple_spgemm_pallas
 from repro.models import lm
 from repro.models.layers import sparse_linear
+from repro.serve import SamplingConfig, jitted_sampler
 
 HBM_BYTES = 15.75e9          # v5e HBM as the compiler reports it
 
@@ -178,3 +179,20 @@ def test_qwen3_4b_head_decode_scores_all_slots_in_one_tile(one_chip,
     temp = folded.memory_analysis().temp_size_in_bytes
     unfolded = _compile(per_slot, *args).memory_analysis().temp_size_in_bytes
     assert temp * 10 < unfolded, (temp, unfolded)
+
+
+def test_qwen3_4b_sampler_is_one_small_program(one_chip):
+    """``jit_sample`` at qwen3-4b's widths, 14 slots of bf16 logits over
+    the padded vocabulary: one program for every slot's draw and finite
+    flag, whose temporaries stay under 16 MB."""
+    cfg = get_config("qwen3-4b")
+    slots = 14
+    compiled = jitted_sampler(SamplingConfig(), cfg.vocab_size).lower(
+        _shape(one_chip, (slots, 1, cfg.vocab_padded), jnp.bfloat16),
+        _shape(one_chip, (slots, 2), jnp.uint32),
+        _shape(one_chip, (), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_sample") \
+        and text.count("HloModule") == 1
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 16e6, temp
